@@ -11,10 +11,10 @@
 
 Each benchmark prints ``name,metric,value`` CSV rows (plus section
 headers).  Simulation benches replay bursty traces through the real
-TokenScale control plane on the analytic cluster model; micro benches time
-the real JAX engines on CPU (note: Pallas kernels execute in interpret
-mode on CPU — wall numbers are correctness artifacts, the TPU story lives
-in the dry-run roofline, EXPERIMENTS.md).
+TokenScale control plane on the analytic cluster model; the micro bench
+times the real JAX engines on whatever backend JAX finds.  Its numbers are
+host wall time: on a CPU they time the jnp path and say nothing about a
+device.
 """
 from __future__ import annotations
 
@@ -309,11 +309,13 @@ def fig15_h100():
 
 
 # ---------------------------------------------------------------------------
-# Engine micro-benchmarks (CPU wall time; us_per_call)
+# Engine micro-benchmarks (host wall time; us_per_call)
 # ---------------------------------------------------------------------------
 
 def engine_microbench():
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import decode_step, init_params, init_state, prefill
+    enable_compile_cache()
     cfg = get_config("llama31_8b", smoke=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
     B, S = 4, 64

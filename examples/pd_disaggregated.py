@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import CHIPS, InstanceSpec, TokenScalePolicy, profile
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import PDCluster, Request
 
@@ -57,6 +58,7 @@ def parse_engine(argv):
 
 def main():
     engine = parse_engine(sys.argv[1:])
+    enable_compile_cache()
     cfg = get_config("llama-3.1-8b", smoke=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
     prof = profile(get_config("llama-3.1-8b"), InstanceSpec(CHIPS["v5e"], 4))

@@ -26,7 +26,8 @@ def chunked_prefill_attention_ref(
 
     Row t of q sits at absolute position offset+t; keys are cache slots
     0..Skv-1; a key is visible iff k_pos <= q_pos and k_pos < lengths
-    (and within the sliding window if window > 0)."""
+    (and within the sliding window if window > 0).  A row that sees no
+    key (only padding rows can) is 0."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -43,7 +44,9 @@ def chunked_prefill_attention_ref(
     if window:
         mask &= k_pos[:, None, :] > (q_pos[:, :, None] - window)
     s = jnp.where(mask[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    # a row that sees no key at all (padding past the window) outputs 0
+    p = jnp.where(mask.any(-1)[:, None, None, :, None],
+                  jax.nn.softmax(s, axis=-1), 0.0)
     o = jnp.einsum("bkgql,blkd->bqkgd", p, v.astype(jnp.float32))
     return o.reshape(B, Sq, Hq, D).astype(q.dtype)
 

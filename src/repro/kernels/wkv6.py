@@ -42,11 +42,18 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)               # (1, K) block
+    u = u_ref[0].astype(jnp.float32)               # (1, K)
     s0 = s_scr[...]                                # (K, K)
 
     logw = jnp.log(jnp.maximum(w, 1e-38))          # (C, K), <= 0
-    L = jnp.cumsum(logw, axis=0)                   # inclusive prefix
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum); HIGHEST keeps it f32-exact on the MXU
+    tril = (s_idx <= t_idx).astype(jnp.float32)
+    L = jax.lax.dot_general(tril, logw, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     L_prev = L - logw                              # exclusive prefix (L_{t-1})
 
     q_in = r * jnp.exp(L_prev)                     # (C, K)
@@ -57,11 +64,9 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     # intra-chunk: strictly-causal scores + bonus diagonal
     scores = jax.lax.dot_general(q_in, k_out, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(s_idx < t_idx, scores, 0.0)
-    diag = jnp.sum(r * u * k, axis=1)              # (C,)
-    scores += jnp.where(s_idx == t_idx, diag[:, None], 0.0)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)   # (C, 1)
+    scores += jnp.where(s_idx == t_idx, diag, 0.0)
     y += jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     y_ref[0, 0] = y.astype(y_ref.dtype)
@@ -94,7 +99,7 @@ def wkv6(r, k, v, w, u, s0, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, ci: (b, h, ci, 0)),
-            pl.BlockSpec((1, K), lambda b, h, ci: (h, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, h, ci: (h, 0, 0)),
             pl.BlockSpec((1, 1, K, K), lambda b, h, ci: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -107,5 +112,5 @@ def wkv6(r, k, v, w, u, s0, chunk: int = DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(H, 1, K), s0)
     return y, sT

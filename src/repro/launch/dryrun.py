@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs import (ARCH_IDS, INPUT_SHAPES, InputShape, ModelConfig,
                            get_config, input_specs, long_context_variant)
-from repro.launch.mesh import (act_rules, batch_axes, compat_set_mesh,
+from repro.launch.mesh import (act_rules, batch_axes,
                                make_production_mesh, needs_fsdp, param_rules)
 from repro.launch.roofline import (Roofline, analyze_hlo,
                                    model_flops_estimate)
@@ -190,7 +190,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
     fn, args, shards, cfg, mesh, rules_a, shape = built
     t0 = time.time()
     try:
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             with axis_rules(rules_a, mesh):
                 lowered = jax.jit(fn, in_shardings=shards).lower(*args)
             t_lower = time.time() - t0

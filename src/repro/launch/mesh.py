@@ -28,34 +28,11 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.core.hardware import V5E, weight_bytes
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; older ones
-    default to Auto semantics anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def compat_set_mesh(mesh):
-    """``jax.set_mesh`` across JAX versions.  Older releases spell it
-    ``jax.sharding.use_mesh`` or simply use the Mesh as a context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(multi_pod: bool) -> tuple[str, ...]:

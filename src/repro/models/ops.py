@@ -18,9 +18,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kops
 from repro.models.params import _RWKV_LORA  # lora width shared with decls
-from repro.sharding import (compat_shard_map, current_mesh, current_rules,
-                            shard)
+from repro.sharding import current_mesh, current_rules, shard
 
 NEG_INF = -2.0 ** 30
 
@@ -134,10 +134,15 @@ def _sdpa(q, k, v, mask, scale, cap: float = 0.0, merged: bool = True):
     return shard(out, "batch", None, "heads", None)
 
 
-def _kernels():
-    """Deferred import: Pallas kernels are optional at model-exec time."""
-    from repro.kernels import ops as kops
-    return kops
+def _kernels_allowed(ctx: ApplyCtx) -> bool:
+    """Whether this computation may take the Pallas kernels, which
+    ``kops.by_platform`` then picks where it is lowered for a TPU.  Training
+    keeps the jnp path (the kernels have no VJP), and so does every
+    computation under a mesh, the repo's ``axis_rules`` or ``jax.set_mesh``:
+    the kernels are not wrapped in ``shard_map``, so GSPMD could only
+    replicate a ``pallas_call`` whole, cache included."""
+    return (ctx.mode != "train" and current_mesh() is None
+            and jax.sharding.get_abstract_mesh().empty)
 
 
 def _heads_shardable(n_heads: int) -> bool:
@@ -156,18 +161,18 @@ def _heads_shardable(n_heads: int) -> bool:
     return n <= 1 or n_heads % n == 0
 
 
-def _pallas_attn(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale):
-    """Route attention through the Pallas kernels (REPRO_USE_PALLAS=1).
+def _pallas_attn(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale,
+                 interpret: bool):
+    """Attention through the Pallas kernels against the merged-head cache.
 
-    train/prefill -> chunked_prefill_attention (offset = chunk start);
-    decode        -> flash-decode."""
-    kops = _kernels()
-    B, S = q.shape[0], q.shape[1]
+    prefill -> chunked_prefill_attention (offset = chunk start);
+    decode  -> flash-decode."""
+    B = q.shape[0]
     if ctx.mode == "decode":
         return kops.decode_attention_op(
             q[:, 0], kc, vc, ctx.positions[:, 0],
             window=ctx.window, softcap=float(cfg.attn_softcap),
-            scale=scale)[:, None]
+            scale=scale, interpret=interpret)[:, None]
     offset = ctx.positions[:, 0]
     if ctx.lengths is not None:
         lengths = ctx.lengths
@@ -175,7 +180,19 @@ def _pallas_attn(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale):
         lengths = jnp.full((B,), kc.shape[1], jnp.int32)
     return kops.prefill_attention(
         q, kc, vc, offset, lengths, window=ctx.window,
-        softcap=float(cfg.attn_softcap), scale=scale)
+        softcap=float(cfg.attn_softcap), scale=scale, interpret=interpret)
+
+
+def _cache_sdpa(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale):
+    """The jnp path (and the kernels' reference): attention against the
+    merged-head cache, grouped so the cache layout stays undisturbed."""
+    B, L = kc.shape[:2]
+    nkv, dh = cfg.num_kv_heads, cfg.head_dim_
+    kc = shard(kc.reshape(B, L, nkv, dh), "batch", "ctx", "kv_heads", None)
+    vc = shard(vc.reshape(B, L, nkv, dh), "batch", "ctx", "kv_heads", None)
+    k_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    mask = _causal_mask(ctx, ctx.positions, k_pos, ctx.lengths, ctx.window)
+    return _sdpa(q, kc, vc, mask, scale, cfg.attn_softcap, merged=False)
 
 
 def _causal_mask(ctx: ApplyCtx, q_pos, k_pos, k_len=None, window: int = 0):
@@ -213,50 +230,50 @@ def apply_attn(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
 
     new_state = state
     if ctx.mode == "train":
-        k_pos = ctx.positions
-        mask = _causal_mask(ctx, ctx.positions, k_pos,
+        mask = _causal_mask(ctx, ctx.positions, ctx.positions,
                             ctx.lengths, ctx.window)
-        kc, vc = k, v
+        out = _sdpa(q, shard(k, "batch", "ctx", "kv_heads", None),
+                    shard(v, "batch", "ctx", "kv_heads", None), mask, scale,
+                    cfg.attn_softcap, merged=_heads_shardable(nq))
     else:
         # write offset = absolute position of the first new token
         # (0 for whole-prompt prefill, chunk start for chunked prefill,
-        #  cur_len for decode)
+        #  cur_len for decode).  The cache is merged-head (B, L, Hkv*D):
+        # token-major with no padded minor dims, the kernels' layout.
         write_idx = ctx.positions[:, 0]
-        # reshard the new K/V to the CACHE layout before the in-place
-        # update: without this GSPMD falls back to "involuntary full
-        # rematerialization" (a whole-cache f32 all-gather per layer,
-        # 722 GB/chip on gemma2 prefill — EXPERIMENTS.md §Perf)
-        k = shard(k, "batch", "ctx", "kv_heads", None)
-        v = shard(v, "batch", "ctx", "kv_heads", None)
+        merge = lambda a: a.reshape(*a.shape[:2], -1)  # noqa: E731
         if cfg.kv_cache_dtype == "int8":
             # quantized KV cache: per-(token, head) absmax scales
             kq, ks = _quant_kv(k)
             vq, vs = _quant_kv(v)
-            kcq = _update_cache(state["k"], kq, write_idx)
-            vcq = _update_cache(state["v"], vq, write_idx)
+            kcq = _update_cache(state["k"], merge(kq), write_idx)
+            vcq = _update_cache(state["v"], merge(vq), write_idx)
             kss = _update_cache(state["k_scale"], ks, write_idx)
             vss = _update_cache(state["v_scale"], vs, write_idx)
             new_state = {**state, "k": kcq, "v": vcq,
                          "k_scale": kss, "v_scale": vss}
-            kc = (kcq.astype(x.dtype)
-                  * kss[..., None].astype(x.dtype))
-            vc = (vcq.astype(x.dtype)
-                  * vss[..., None].astype(x.dtype))
+            dq = lambda c, sc: merge(  # noqa: E731
+                c.reshape(*c.shape[:2], nkv, dh).astype(x.dtype)
+                * sc[..., None].astype(x.dtype))
+            kc, vc = dq(kcq, kss), dq(vcq, vss)
         else:
-            kc = _update_cache(state["k"], k, write_idx)
-            vc = _update_cache(state["v"], v, write_idx)
+            # reshard the new K/V to the CACHE layout before the in-place
+            # update: without this GSPMD falls back to "involuntary full
+            # rematerialization" (a whole-cache f32 all-gather per layer,
+            # 722 GB/chip on gemma2 prefill)
+            kc = _update_cache(state["k"], shard(merge(k), "batch", "ctx",
+                                                 "kv_heads"), write_idx)
+            vc = _update_cache(state["v"], shard(merge(v), "batch", "ctx",
+                                                 "kv_heads"), write_idx)
             new_state = {**state, "k": kc, "v": vc}
-        L = kc.shape[1]
-        k_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-        mask = _causal_mask(ctx, ctx.positions, k_pos,
-                            ctx.lengths, ctx.window)
-    kc = shard(kc, "batch", "ctx", "kv_heads", None)
-    vc = shard(vc, "batch", "ctx", "kv_heads", None)
-    if _kernels().use_pallas():
-        out = _pallas_attn(cfg, q, kc, vc, ctx, scale)
-    else:
-        out = _sdpa(q, kc, vc, mask, scale, cfg.attn_softcap,
-                    merged=(ctx.mode == "train" and _heads_shardable(nq)))
+        reference = lambda: _cache_sdpa(  # noqa: E731
+            cfg, q, kc, vc, ctx, scale)
+        if _kernels_allowed(ctx):
+            out = kops.by_platform(
+                lambda interpret: _pallas_attn(cfg, q, kc, vc, ctx, scale,
+                                               interpret), reference)
+        else:
+            out = reference()
     out = out.reshape(B, S, nq * dh) @ p["wo"]
     if cfg.post_norms:
         out = rmsnorm(out, p["ln1_post"], cfg.norm_plus_one)
@@ -489,8 +506,9 @@ def _moe_ep_path(cfg: ModelConfig, p, h, mesh, ep_axes):
                 P(ep_axes, None, ff_axes or None),
                 P(ep_axes, ff_axes or None, None))
     out_specs = (P(bspec), P())
-    return compat_shard_map(
+    return jax.shard_map(
         local_moe, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )(h, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
 
@@ -709,12 +727,15 @@ def apply_rwkv_tm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
         k = k * m
     s0 = (state["wkv"].astype(jnp.float32) if state is not None
           else jnp.zeros((B, H, K, K), jnp.float32))
-    if _kernels().use_pallas() and S > 1:
-        y, sT = _kernels().wkv6_op(r, k, v, w, u, s0)
-    elif S > 1:
-        y, sT = rwkv_wkv_chunked(r, k, v, w, u, s0)
-    else:
+    if S == 1:
         y, sT = rwkv_wkv(r, k, v, w, u, s0)
+    elif _kernels_allowed(ctx):
+        y, sT = kops.by_platform(
+            lambda interpret: kops.wkv6_op(r, k, v, w, u, s0,
+                                           interpret=interpret),
+            lambda: rwkv_wkv_chunked(r, k, v, w, u, s0))
+    else:
+        y, sT = rwkv_wkv_chunked(r, k, v, w, u, s0)
     # per-head groupnorm
     mu = y.mean(-1, keepdims=True)
     var = y.var(-1, keepdims=True)

@@ -221,11 +221,13 @@ def layer_state_leaves(cfg: ModelConfig, spec: LayerSpec, batch: int,
                                ("batch", "ctx", None), "zeros", cdt),
             }
         kv_dt = cfg.kv_cache_dtype or cdt
+        # merged-head (B, L, Hkv*D): token-major, so a (L, Hkv*D) tile has
+        # no padded minor dim and the decode kernel reads it as stored
         t = {
-            "k": Leaf((batch, max_len, nkv, dh),
-                      ("batch", "ctx", "kv_heads", None), "zeros", kv_dt),
-            "v": Leaf((batch, max_len, nkv, dh),
-                      ("batch", "ctx", "kv_heads", None), "zeros", kv_dt),
+            "k": Leaf((batch, max_len, nkv * dh),
+                      ("batch", "ctx", "kv_heads"), "zeros", kv_dt),
+            "v": Leaf((batch, max_len, nkv * dh),
+                      ("batch", "ctx", "kv_heads"), "zeros", kv_dt),
         }
         if kv_dt == "int8":
             t["k_scale"] = Leaf((batch, max_len, nkv),

@@ -8,17 +8,17 @@ periodically evaluates the TokenScale policy against live Observations and
 boots/retires instances.  Everything is the same `repro.core` control-plane
 code the simulator drives — here it orchestrates actual model execution.
 
-This is the CPU-scale twin of the production deployment: instances share a
-process (and weights) instead of owning TPU slices, and the virtual clock
-advances by measured wall time of each engine step.
+All instances share one process, one device and one copy of the weights
+(on a TPU they run the Pallas kernels, elsewhere the jnp path), and the
+virtual clock advances by the measured wall time of each cluster step.
 """
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -27,9 +27,9 @@ from repro.core.autoscaler import Observation, Policy
 from repro.core.predictor import OutputPredictor
 from repro.core.router import BurstDetector, Router, ttft_slo
 from repro.core.velocity import bucket_of
-from repro.models import init_state, prefill
+from repro.models import init_state
 from repro.serving import kvtransfer
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, Request, programs
 from repro.serving.kvtransfer import TransferStats
 
 
@@ -44,8 +44,7 @@ class PrefillerInstance:
         self.queue: list[Request] = []
         self.tokens_done = 0
         self.wall_s = 1e-9
-        self._fn = jax.jit(
-            lambda p, s, t, ln: prefill(cfg, p, s, t, ln))
+        self._fn = programs(cfg)[0]
 
     # Alg. 1 interface -------------------------------------------------
     def inflight_tokens(self) -> float:
@@ -74,7 +73,8 @@ class PrefillerInstance:
         st = init_state(self.cfg, 1, self.max_len)
         t0 = time.perf_counter()
         logits, st = self._fn(self.params, st, jnp.asarray(toks),
-                              jnp.array([L], jnp.int32))
+                              jnp.array([L], jnp.int32), None,
+                              jnp.zeros((1,), jnp.int32))
         logits.block_until_ready()
         self.wall_s += time.perf_counter() - t0
         self.tokens_done += L
@@ -268,17 +268,38 @@ class PDCluster:
 
     # ------------------------------------------------------------------
     def run_until_drained(self, max_steps: int = 2000,
-                          autoscale_every: int = 10):
+                          autoscale_every: int = 10,
+                          arrivals: Sequence[tuple[float, Request]] = ()
+                          ) -> dict:
+        """Step (and autoscale every `autoscale_every` steps) until every
+        request has finished.
+
+        `arrivals` are (due time, request) pairs in due order, each
+        submitted once the clock reaches its due time; while the cluster is
+        idle the clock skips ahead to the next one.  Returns the cluster
+        steps taken and the peak prefiller and decoder counts."""
+        todo = deque(arrivals)
         steps = 0
-        while self._busy():
+        peak = {"prefillers": len(self.prefillers),
+                "decoders": len(self.decoders)}
+        while todo or self.busy():
+            while todo and todo[0][0] <= self.now:
+                self.submit(todo.popleft()[1])
+            if not self.busy():
+                self.now = todo[0][0]        # idle until the next arrival
+                continue
             self.step()
             steps += 1
             if steps % autoscale_every == 0:
                 self.autoscale()
+            peak["prefillers"] = max(peak["prefillers"], len(self.prefillers))
+            peak["decoders"] = max(peak["decoders"], len(self.decoders))
             if steps > max_steps:
                 raise RuntimeError("PD cluster did not drain")
+        return {"steps": steps, "peak_prefillers": peak["prefillers"],
+                "peak_decoders": peak["decoders"]}
 
-    def _busy(self) -> bool:
+    def busy(self) -> bool:
         if self.pending:
             return True
         if any(p.queue for p in self.prefillers):

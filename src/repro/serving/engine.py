@@ -6,7 +6,8 @@ slots backed by a preallocated per-slot cache/state (the TPU analogue of
 vLLM's paged KV pool — slot-contiguous rather than paged, page granularity
 traded for XLA-static shapes; see DESIGN.md).
 
-Three jitted programs:
+Three jitted programs, built once per config by ``programs`` and shared by
+every instance of it:
 
   * ``_prefill``      whole-prompt prefill of one request (batch-1 state)
   * ``_decode``       one token for every active slot
@@ -21,6 +22,7 @@ lives in ``repro.core.convertible``.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Optional
@@ -111,6 +113,32 @@ def _read_slot(pool, slot):
     return jax.tree_util.tree_map_with_path(per_leaf, pool)
 
 
+@functools.lru_cache(maxsize=None)
+def programs(cfg: ModelConfig):
+    """The three jitted programs of every instance serving `cfg`.
+
+    Built once per config and shared, so an instance the autoscaler boots
+    reuses what earlier instances traced and compiled for the same shapes."""
+
+    def _prefill_one(params, state1, tokens, length, image_embeds, start):
+        return prefill(cfg, params, state1, tokens, length,
+                       image_embeds=image_embeds, start=start)
+
+    def _decode_all(params, state, last_tokens, cur_lens):
+        return decode_step(cfg, params, state, last_tokens, cur_lens)
+
+    def _mixed(params, state, last_tokens, cur_lens,
+               chunk_state, chunk_tokens, chunk_len, chunk_start):
+        """Fused decode + restricted prefill chunk (single XLA program)."""
+        logits, new_state = decode_step(cfg, params, state,
+                                        last_tokens, cur_lens)
+        clog, new_cstate = prefill(cfg, params, chunk_state, chunk_tokens,
+                                   chunk_len, start=chunk_start)
+        return logits, new_state, clog, new_cstate
+
+    return jax.jit(_prefill_one), jax.jit(_decode_all), jax.jit(_mixed)
+
+
 class Engine:
     """A single inference instance with `num_slots` concurrent requests."""
 
@@ -129,31 +157,8 @@ class Engine:
         self.waiting: list[Request] = []
         self.pending_chunked: Optional[Request] = None
         self.now = 0.0                        # virtual clock (tests/sim)
-        self._build()
-
-    # ------------------------------------------------------------------
-    def _build(self):
-        cfg = self.cfg
-
-        def _prefill_one(params, state1, tokens, length, image_embeds, start):
-            return prefill(cfg, params, state1, tokens, length,
-                           image_embeds=image_embeds, start=start)
-
-        def _decode_all(params, state, last_tokens, cur_lens):
-            return decode_step(cfg, params, state, last_tokens, cur_lens)
-
-        def _mixed(params, state, last_tokens, cur_lens,
-                   chunk_state, chunk_tokens, chunk_len, chunk_start):
-            """Fused decode + restricted prefill chunk (single XLA program)."""
-            logits, new_state = decode_step(cfg, params, state,
-                                            last_tokens, cur_lens)
-            clog, new_cstate = prefill(cfg, params, chunk_state, chunk_tokens,
-                                       chunk_len, start=chunk_start)
-            return logits, new_state, clog, new_cstate
-
-        self._prefill = jax.jit(_prefill_one)
-        self._decode = jax.jit(_decode_all)
-        self._mixed = jax.jit(_mixed)
+        self.steps = {"decode": 0, "mixed": 0}
+        self._prefill, self._decode, self._mixed = programs(cfg)
 
     # ------------------------------------------------------------------
     def free_slots(self) -> int:
@@ -170,12 +175,11 @@ class Engine:
         if self.free_slots() == 0:
             return False
         slot = self._alloc_slot(req)
-        import time as _t
-        t0 = _t.perf_counter()
+        t0 = time.perf_counter()
         nbytes = kvtransfer.payload_bytes(payload)
         self.state = kvtransfer.insert(self.cfg, self.state, payload, slot)
         if stats is not None:
-            stats.record(nbytes, payload.length, _t.perf_counter() - t0)
+            stats.record(nbytes, payload.length, time.perf_counter() - t0)
         self.last_tokens[slot] = first_token
         self.cur_lens[slot] = payload.length
         req.prefill_done = payload.length
@@ -238,8 +242,10 @@ class Engine:
 
         if self.pending_chunked is not None:
             emitted += self._step_mixed()
+            self.steps["mixed"] += 1
         elif self.active.any():
             emitted += self._step_decode()
+            self.steps["decode"] += 1
         self._drain_waiting()
         return emitted
 

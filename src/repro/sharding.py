@@ -103,21 +103,6 @@ def logical_to_pspec(axes: tuple[Optional[str], ...],
     return P(*parts)
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX versions: the top-level spelling (with
-    ``check_vma``) only exists on newer releases; older ones ship it as
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:       # top-level spelling but pre-check_vma kwarg
-            pass
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def shard(x, *axes: Optional[str]):
     """Annotate an activation with logical axes; no-op without rules/mesh."""
     rules = current_rules()

@@ -93,6 +93,29 @@ def test_pd_cluster_exact_outputs(setup):
     assert cl.transfers.total_bytes > 0
 
 
+def test_pd_cluster_replays_arrivals(setup):
+    """Arrivals are submitted at their due times; an idle cluster's clock
+    skips ahead to the next one, and every request drains."""
+    cfg, params = setup
+    prof = profile(get_config("llama31_8b"), InstanceSpec(CHIPS["v5e"], 1))
+    cl = PDCluster(cfg, params, TokenScalePolicy(prof, convertible=1),
+                   n_prefillers=1, n_decoders=1, n_convertible=1,
+                   max_len=64)
+    rng = np.random.RandomState(3)
+    arrivals = [(due, Request(rid=i,
+                              prompt=rng.randint(0, cfg.vocab_size,
+                                                 size=(9,)).astype(np.int32),
+                              max_new_tokens=3))
+                for i, due in enumerate([0.0, 0.0, 1000.0])]
+    ran = cl.run_until_drained(arrivals=arrivals)
+    reqs = [r for _, r in arrivals]
+    assert [len(r.output) for r in reqs] == [3, 3, 3]
+    assert reqs[0].arrival_t == 0.0 and reqs[2].arrival_t == 1000.0
+    assert cl.now > 1000.0 and not cl.busy()
+    assert ran["steps"] > 0
+    assert ran["peak_prefillers"] >= 1 and ran["peak_decoders"] >= 1
+
+
 def test_pd_cluster_autoscales(setup):
     cfg, params = setup
     prof = profile(get_config("llama31_8b"), InstanceSpec(CHIPS["v5e"], 1))

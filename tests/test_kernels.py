@@ -17,6 +17,11 @@ def _rnd(*shape, dtype=np.float32):
     return jnp.asarray(RNG.randn(*shape).astype(dtype))
 
 
+def _merged(x):
+    """(B, L, Hkv, D) -> the cache's merged-head (B, L, Hkv*D) layout."""
+    return x.reshape(*x.shape[:2], -1)
+
+
 # ---------------------------------------------------------------------------
 # chunked prefill attention
 # ---------------------------------------------------------------------------
@@ -40,7 +45,8 @@ def test_chunked_prefill_attention(B, Sq, Skv, Hq, Hkv, D, window, cap):
     v = _rnd(B, Skv, Hkv, D)
     off = jnp.asarray(RNG.randint(0, Skv - Sq + 1, size=(B,)), jnp.int32)
     lens = jnp.asarray(RNG.randint(1, Skv + 1, size=(B,)), jnp.int32)
-    out = prefill_attention(q, k, v, off, lens, window=window, softcap=cap)
+    out = prefill_attention(q, _merged(k), _merged(v), off, lens,
+                            window=window, softcap=cap, interpret=True)
     want = ref.chunked_prefill_attention_ref(q, k, v, off, lens,
                                              window=window, softcap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -54,7 +60,8 @@ def test_chunked_prefill_attention_bf16():
     v = _rnd(B, Skv, Hkv, D).astype(jnp.bfloat16)
     off = jnp.zeros((B,), jnp.int32)
     lens = jnp.full((B,), Skv, jnp.int32)
-    out = prefill_attention(q, k, v, off, lens)
+    out = prefill_attention(q, _merged(k), _merged(v), off, lens,
+                            interpret=True)
     want = ref.chunked_prefill_attention_ref(q, k, v, off, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -80,7 +87,8 @@ def test_prefill_attention_property(b, sq, extra, hkv, g, d):
     v = jnp.asarray(rng.randn(b, skv, hkv, d).astype(np.float32))
     off = jnp.asarray(rng.randint(0, extra + 1, size=(b,)), jnp.int32)
     lens = jnp.asarray(rng.randint(1, skv + 1, size=(b,)), jnp.int32)
-    out = prefill_attention(q, k, v, off, lens)
+    out = prefill_attention(q, _merged(k), _merged(v), off, lens,
+                            interpret=True)
     want = ref.chunked_prefill_attention_ref(q, k, v, off, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -102,7 +110,8 @@ def test_decode_attention(B, L, Hq, Hkv, D, window):
     k = _rnd(B, L, Hkv, D)
     v = _rnd(B, L, Hkv, D)
     cur = jnp.asarray(RNG.randint(0, L, size=(B,)), jnp.int32)
-    out = decode_attention_op(q, k, v, cur, window=window, block_k=32)
+    out = decode_attention_op(q, _merged(k), _merged(v), cur,
+                              window=window, block_k=32, interpret=True)
     want = ref.decode_attention_ref(q, k, v, cur, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -116,10 +125,12 @@ def test_decode_attention_block_skipping():
     k = _rnd(B, L, H, D)
     v = _rnd(B, L, H, D)
     cur = jnp.array([10], jnp.int32)
-    out1 = decode_attention_op(q, k, v, cur, block_k=16)
+    out1 = decode_attention_op(q, _merged(k), _merged(v), cur, block_k=16,
+                               interpret=True)
     k2 = k.at[:, 20:].set(jnp.nan)
     v2 = v.at[:, 20:].set(jnp.nan)
-    out2 = decode_attention_op(q, k2, v2, cur, block_k=16)
+    out2 = decode_attention_op(q, _merged(k2), _merged(v2), cur,
+                               block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
 
@@ -146,7 +157,7 @@ def _wkv_inputs(B, S, H, K, seed=0):
 ])
 def test_wkv6(B, S, H, K, chunk):
     r, k, v, w, u, s0 = _wkv_inputs(B, S, H, K, seed=B * 100 + S)
-    y, sT = wkv6_op(r, k, v, w, u, s0, chunk=chunk)
+    y, sT = wkv6_op(r, k, v, w, u, s0, chunk=chunk, interpret=True)
     tr = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     y_ref, sT_ref = ref.wkv6_ref(tr(r), tr(k), tr(v), tr(w), u, s0)
     np.testing.assert_allclose(np.asarray(tr(y)), np.asarray(y_ref),
@@ -160,12 +171,12 @@ def test_wkv6_state_carry_composes():
     (the chunked-prefill invariant for SSM layers)."""
     B, S, H, K = 1, 32, 2, 16
     r, k, v, w, u, s0 = _wkv_inputs(B, S, H, K, seed=7)
-    y_full, sT_full = wkv6_op(r, k, v, w, u, s0)
+    y_full, sT_full = wkv6_op(r, k, v, w, u, s0, interpret=True)
     half = S // 2
     y1, s_mid = wkv6_op(r[:, :half], k[:, :half], v[:, :half], w[:, :half],
-                        u, s0)
+                        u, s0, interpret=True)
     y2, sT = wkv6_op(r[:, half:], k[:, half:], v[:, half:], w[:, half:],
-                     u, s_mid)
+                     u, s_mid, interpret=True)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(sT), np.asarray(sT_full),
@@ -173,17 +184,31 @@ def test_wkv6_state_carry_composes():
 
 
 def test_model_pallas_path_matches_default(monkeypatch):
-    """End-to-end: REPRO_USE_PALLAS=1 reproduces the jnp model path."""
+    """End-to-end: prefill then decode through the cache on the kernel
+    path (interpreted) reproduces the jnp model path."""
     from repro.configs import get_config
-    from repro.models import forward_train, init_params
+    from repro.kernels import ops as kops
+    from repro.models import decode_step, init_params, init_state, prefill
     for arch in ["llama31_8b", "gemma2_9b", "rwkv6_3b"]:
         cfg = get_config(arch, smoke=True)
         params = init_params(cfg, jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                                   cfg.vocab_size)
-        monkeypatch.setenv("REPRO_USE_PALLAS", "0")
-        a, _ = forward_train(cfg, params, toks)
-        monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-        b, _ = forward_train(cfg, params, toks)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+        lens = jnp.array([12, 9], jnp.int32)
+        runs = []
+        for interpreted in (False, True):
+            monkeypatch.setattr(
+                kops, "by_platform",
+                lambda kernel, reference, on=interpreted:
+                    kernel(True) if on else reference())
+            st = init_state(cfg, 2, 32)
+            lg, st = prefill(cfg, params, st, toks, lens)
+            logits = [lg]
+            tok, cur = jnp.argmax(lg, -1).astype(jnp.int32), lens
+            for _ in range(3):
+                lg, st = decode_step(cfg, params, st, tok, cur)
+                logits.append(lg)
+                tok, cur = jnp.argmax(lg, -1).astype(jnp.int32), cur + 1
+            runs.append(jnp.stack(logits))
+        np.testing.assert_allclose(np.asarray(runs[0]), np.asarray(runs[1]),
                                    atol=2e-4, rtol=2e-4)

@@ -32,10 +32,12 @@ def test_train_cli(tmp_path):
 @pytest.mark.slow
 def test_serve_cli():
     out = _run(["-m", "repro.launch.serve", "--arch", "llama-3.1-8b",
-                "--requests", "4", "--max-new", "4", "--chunk-size", "8"])
+                "--smoke", "--requests", "4", "--max-new", "4",
+                "--chunk-size", "8", "--max-len", "128", "--slots", "4"])
     rec = json.loads(out.strip().splitlines()[-1])
     assert rec["completed"] == 4
-    assert rec["convertible_mode"] is True
+    assert rec["convertibles"] == 1
+    assert rec["kv_transfers"] > 0
 
 
 @pytest.mark.slow

@@ -20,7 +20,6 @@ import os, dataclasses
 import jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models import init_params, forward_train
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 from repro.sharding import axis_rules
 
 cfg = get_config("kimi_k2_1t_a32b", smoke=True)
@@ -29,12 +28,13 @@ params = init_params(cfg, jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
 ref, aux_ref = forward_train(cfg, params, toks)
 
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = {"batch": ("data",), "experts": ("model",), "heads": ("model",),
          "kv_heads": ("model",), "ff": ("model",), "vocab": ("model",),
          "embed": (), "ctx": (), "kv_lora": (), "seq": (), "state": ()}
 with axis_rules(rules, mesh):
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(lambda p, t: forward_train(cfg, p, t))(params, toks)
 err = float(jnp.abs(out - ref).max())
 assert err < 1e-4, f"EP path diverged: {err}"

@@ -76,7 +76,7 @@ def test_wkv6_zero_key_is_identity(b, s, h, k):
     w = jnp.full((b, s, h, k), 0.5, jnp.float32)
     u = jnp.asarray(rng.randn(h, k).astype(np.float32))
     s0 = jnp.asarray(rng.randn(b, h, k, k).astype(np.float32))
-    y, sT = wkv6_op(r, kk, v, w, u, s0)
+    y, sT = wkv6_op(r, kk, v, w, u, s0, interpret=True)
     want = np.asarray(s0) * (0.5 ** s)
     np.testing.assert_allclose(np.asarray(sT), want, atol=1e-4, rtol=1e-4)
 

@@ -23,8 +23,8 @@ def test_logical_to_pspec_basic():
 
 def test_logical_to_pspec_divisibility_drop():
     """4 KV heads cannot shard over a 16-way model axis -> replicated."""
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rules = {"kv_heads": ("model",)}
 
     class FakeMesh:
