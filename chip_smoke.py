@@ -173,7 +173,7 @@ def main(argv=None) -> int:
     from repro.configs import get_config
     from repro.launch.compile_cache import CompileLog, enable_compile_cache
     from repro.models import abstract_state, init_params
-    from repro.serving.engine import programs
+    from repro.serving.engine import jitted, programs
 
     devs = jax.devices()
     dev = devs[0]
@@ -205,7 +205,7 @@ def main(argv=None) -> int:
     results["B"] = phase_b(cfg, params, SLOTS, MAX_LEN, prompt_len=300,
                            steps=8, seed=seed)
     lens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
-    decode_hlo = programs(cfg)[1].lower(
+    decode_hlo = jitted(programs(cfg)[1]).lower(
         params, abstract_state(cfg, SLOTS, MAX_LEN), lens, lens).as_text()
     on_kernels = "tpu_custom_call" in decode_hlo
     log(f"  decode program calls Pallas kernels: {on_kernels}")

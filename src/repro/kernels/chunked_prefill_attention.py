@@ -19,6 +19,10 @@ query).  The kv_block axis is innermost and sequential, so the
 online-softmax stats (m, l) and the output accumulator live in VMEM scratch
 across kv steps.
 
+The cache may be the model's whole stack of layers, (NB, P, Skv, Hkv*D):
+the layer index and each batch row's pool row (`slots`) are scalar-prefetched
+and the index maps read (layer, slots[b], block) where it lies.
+
 Per-batch `offset` and `lengths` are scalar-prefetched.  Masking is computed
 from broadcasted iotas against absolute positions, which is what lets the
 SAME kernel serve both the prefiller instances and the convertible decoder's
@@ -61,7 +65,7 @@ def _live_blocks(offset, length, qi, bq: int, bk: int, window: int):
     return lo, hi
 
 
-def _kernel(off_ref, len_ref,                      # scalar prefetch (SMEM)
+def _kernel(off_ref, len_ref, layer_ref, slot_ref,  # scalar prefetch (SMEM)
             q_ref, k_ref, v_ref,                   # VMEM blocks
             o_ref,                                 # VMEM out block
             acc, m_s, l_s,                         # scratch
@@ -93,8 +97,8 @@ def _kernel(off_ref, len_ref,                      # scalar prefetch (SMEM)
             mask &= k_pos > (q_pos - window)
         for h in range(hb):                        # static: KV heads here
             q = q_ref[0, h * group:(h + 1) * group].reshape(rows, d)
-            k = k_ref[0, :, h * d:(h + 1) * d]             # (BK, D)
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            k = k_ref[0, 0, :, h * d:(h + 1) * d]          # (BK, D)
+            v = v_ref[0, 0, :, h * d:(h + 1) * d]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
@@ -124,18 +128,22 @@ def _kernel(off_ref, len_ref,                      # scalar prefetch (SMEM)
 
 def chunked_prefill_attention(
         q: jax.Array,            # (B, Hq, Sq, D) head-major
-        k: jax.Array,            # (B, Skv, Hkv*D) merged-head cache
+        k: jax.Array,            # (B, Skv, Hkv*D) or (NB, P, Skv, Hkv*D)
         v: jax.Array,
         offset: jax.Array,       # (B,) int32 absolute position of q row 0
         lengths: jax.Array,      # (B,) int32 absolute valid key length
+        layer: Optional[jax.Array] = None,   # () int32 into NB; default 0
+        slots: Optional[jax.Array] = None,   # (B,) int32 into P; default b
         window: int = 0,
         softcap: float = 0.0,
         scale: Optional[float] = None,
         block_q: int = DEFAULT_BQ,
         block_k: int = DEFAULT_BK,
         interpret: bool = False) -> jax.Array:
+    if k.ndim == 3:
+        k, v = k[None], v[None]
     B, Hq, Sq, D = q.shape
-    Skv, HD = k.shape[1], k.shape[2]
+    P, Skv, HD = k.shape[1:]
     Hkv = HD // D
     assert (HD == Hkv * D and Hq % Hkv == 0 and Sq % block_q == 0
             and Skv % block_k == 0 and block_q & (block_q - 1) == 0), (
@@ -144,21 +152,26 @@ def chunked_prefill_attention(
     group = Hq // Hkv
     hb = heads_per_block(Hkv, D)
 
-    def kv_index(b, h, qi, ki, off, lens):
+    layer = jnp.zeros((1,), jnp.int32) if layer is None else (
+        jnp.reshape(layer, (1,)).astype(jnp.int32))
+    slots = jnp.arange(B, dtype=jnp.int32) if slots is None else (
+        jnp.minimum(slots, P - 1).astype(jnp.int32))
+
+    def kv_index(b, h, qi, ki, off, lens, layer, slots):
         lo, hi = _live_blocks(off[b], lens[b], qi, block_q, block_k, window)
-        return b, jnp.clip(ki, lo, hi), h
+        return layer[0], slots[b], jnp.clip(ki, lo, hi), h
 
     kern = functools.partial(
         _kernel, scale=scale, window=window, softcap=softcap,
         bq=block_q, bk=block_k, hb=hb, group=group, d=D)
     q_spec = pl.BlockSpec((1, hb * group, block_q, D),
-                          lambda b, h, qi, ki, off, lens: (b, h, qi, 0))
-    kv_spec = pl.BlockSpec((1, block_k, hb * D), kv_index)
+                          lambda b, h, qi, ki, *_: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hb * D), kv_index)
     rows = hb * group * block_q
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(B, Hkv // hb, Sq // block_q, Skv // block_k),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
@@ -174,4 +187,5 @@ def chunked_prefill_attention(
                                  "arbitrary")),
         interpret=interpret,
         name="chunked_prefill_attention",
-    )(offset.astype(jnp.int32), lengths.astype(jnp.int32), q, k, v)
+    )(offset.astype(jnp.int32), lengths.astype(jnp.int32), layer, slots,
+      q, k, v)

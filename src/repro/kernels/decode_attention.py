@@ -18,6 +18,11 @@ the needed FLOPs, which a bandwidth-bound step has to spare.
 ``cur_lens`` is scalar-prefetched: the K/V index maps clamp the block index
 to the live range, so blocks past cur_len (or behind the window) are neither
 fetched again nor computed (@pl.when) — per-step traffic is O(cur_len).
+
+The cache may be the model's whole stack of layers, ``(NB, P, L, Hkv*D)``:
+the layer index and each batch row's pool row (``slots``) are
+scalar-prefetched too, and the index maps pick ``(layer, slots[b], block)``
+from the buffer as it lies, so no layer or slot is copied out first.
 """
 from __future__ import annotations
 
@@ -40,8 +45,9 @@ def _live_blocks(cur, bk: int, window: int):
     return lo, hi
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
-            *, scale: float, window: int, softcap: float, bk: int):
+def _kernel(len_ref, layer_ref, slot_ref, q_ref, k_ref, v_ref, o_ref,
+            acc, m_s, l_s, *, scale: float, window: int, softcap: float,
+            bk: int):
     b = pl.program_id(0)
     ki = pl.program_id(1)
     cur = len_ref[b]
@@ -57,8 +63,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
     @pl.when((ki >= lo) & (ki <= hi))
     def _compute():
         q = q_ref[0]                                       # (Hq, Hkv*D)
-        k = k_ref[0]                                       # (BK, Hkv*D)
-        v = v_ref[0]
+        k = k_ref[0, 0]                                    # (BK, Hkv*D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap:
@@ -86,16 +92,20 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
 
 def decode_attention(
         q: jax.Array,            # (B, Hq, D)
-        k: jax.Array,            # (B, L, Hkv*D) merged-head cache
+        k: jax.Array,            # (B, L, Hkv*D) or (NB, P, L, Hkv*D) cache
         v: jax.Array,
         cur_lens: jax.Array,     # (B,) int32; the new token sits at cur_len
+        layer: Optional[jax.Array] = None,   # () int32 into NB; default 0
+        slots: Optional[jax.Array] = None,   # (B,) int32 into P; default b
         window: int = 0,
         softcap: float = 0.0,
         scale: Optional[float] = None,
         block_k: int = DEFAULT_BK,
         interpret: bool = False) -> jax.Array:
+    if k.ndim == 3:
+        k, v = k[None], v[None]
     B, Hq, D = q.shape
-    L, HD = k.shape[1], k.shape[2]
+    P, L, HD = k.shape[1:]
     Hkv = HD // D
     assert HD == Hkv * D and Hq % Hkv == 0 and L % block_k == 0, (
         q.shape, k.shape, block_k)
@@ -106,23 +116,33 @@ def decode_attention(
     q_bd = (q[:, :, None, :] * group[None, :, :, None].astype(q.dtype))
     q_bd = q_bd.reshape(B, Hq, HD).astype(k.dtype)
 
-    def kv_index(b, ki, lens):
+    layer = jnp.zeros((1,), jnp.int32) if layer is None else (
+        jnp.reshape(layer, (1,)).astype(jnp.int32))
+    # a padding row (slot past the pool) reads the last row; its output
+    # is not used
+    slots = jnp.arange(B, dtype=jnp.int32) if slots is None else (
+        jnp.minimum(slots, P - 1).astype(jnp.int32))
+
+    def kv_index(b, ki, lens, layer, slots):
         lo, hi = _live_blocks(lens[b], block_k, window)
-        return b, jnp.clip(ki, lo, hi), 0
+        return layer[0], slots[b], jnp.clip(ki, lo, hi), 0
+
+    def q_index(b, ki, lens, layer, slots):
+        return b, 0, 0
 
     kern = functools.partial(_kernel, scale=scale, window=window,
                              softcap=softcap, bk=block_k)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(B, L // block_k),
             in_specs=[
-                pl.BlockSpec((1, Hq, HD), lambda b, ki, lens: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, HD), kv_index),
-                pl.BlockSpec((1, block_k, HD), kv_index),
+                pl.BlockSpec((1, Hq, HD), q_index),
+                pl.BlockSpec((1, 1, block_k, HD), kv_index),
+                pl.BlockSpec((1, 1, block_k, HD), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, Hq, HD), lambda b, ki, lens: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hq, HD), q_index),
             scratch_shapes=[
                 pltpu.VMEM((Hq, HD), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
@@ -134,7 +154,7 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
-    )(cur_lens.astype(jnp.int32), q_bd, k, v)
+    )(cur_lens.astype(jnp.int32), layer, slots, q_bd, k, v)
     # head i's output sits in the lanes of its own KV group
     out = out.reshape(B, Hkv, G, Hkv, D)
     out = jnp.stack([out[:, g, :, g] for g in range(Hkv)], axis=1)

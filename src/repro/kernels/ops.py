@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels, and the path choice.
 
 The wrappers take the model's layouts: queries (B, S, H, D) and KV caches
-merged-head (B, L, Hkv*D), which the kernels read in place (a cache is never
-padded, transposed or copied).  ``by_platform()`` is how the model layer
-chooses, per lowering platform, between the kernels and its jnp path.
+merged-head (B, L, Hkv*D), or the model's stack of them (NB, P, L, Hkv*D)
+with a layer index and pool rows, which the kernels read in place (a cache
+is never padded, transposed or copied).  ``by_platform()`` is how the model
+layer chooses, per lowering platform, between the kernels and its jnp path.
 Interpret mode happens only where a caller passes ``interpret=True``.
 """
 from __future__ import annotations
@@ -64,11 +65,12 @@ def _pad_to(x, mult, axis):
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
                                              "block_q", "block_k",
                                              "interpret"))
-def prefill_attention(q, k, v, offset, lengths, window: int = 0,
-                      softcap: float = 0.0, scale: Optional[float] = None,
-                      block_q: int = 128, block_k: int = 128,
-                      interpret: bool = False):
-    """(B,Sq,Hq,D) chunked/whole prefill against a (B,Skv,Hkv*D) cache.
+def prefill_attention(q, k, v, offset, lengths, layer=None, slots=None,
+                      window: int = 0, softcap: float = 0.0,
+                      scale: Optional[float] = None, block_q: int = 128,
+                      block_k: int = 128, interpret: bool = False):
+    """(B,Sq,Hq,D) chunked/whole prefill against a (B,Skv,Hkv*D) cache, or
+    against `layer` of a stacked (NB,P,Skv,Hkv*D) one at pool rows `slots`.
 
     Only the query (chunk-sized) is transposed and padded; the cache is read
     in place."""
@@ -76,21 +78,24 @@ def prefill_attention(q, k, v, offset, lengths, window: int = 0,
     bq = _block(Sq, block_q)
     out = chunked_prefill_attention(
         _pad_to(q.transpose(0, 2, 1, 3), bq, 2), k, v, offset, lengths,
-        window=window, softcap=softcap, scale=scale, block_q=bq,
-        block_k=_cache_block(k.shape[1], block_k), interpret=interpret)
+        layer, slots, window=window, softcap=softcap, scale=scale,
+        block_q=bq, block_k=_cache_block(k.shape[-2], block_k),
+        interpret=interpret)
     return out[:, :, :Sq].transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
                                              "block_k", "interpret"))
-def decode_attention_op(q, k, v, cur_lens, window: int = 0,
-                        softcap: float = 0.0, scale: Optional[float] = None,
-                        block_k: int = 512, interpret: bool = False):
-    """(B,Hq,D) single-token decode against a (B,L,Hkv*D) cache, read in
+def decode_attention_op(q, k, v, cur_lens, layer=None, slots=None,
+                        window: int = 0, softcap: float = 0.0,
+                        scale: Optional[float] = None, block_k: int = 512,
+                        interpret: bool = False):
+    """(B,Hq,D) single-token decode against a (B,L,Hkv*D) cache, or against
+    `layer` of a stacked (NB,P,L,Hkv*D) one at pool rows `slots`, read in
     place."""
-    return decode_attention(q, k, v, cur_lens, window=window,
+    return decode_attention(q, k, v, cur_lens, layer, slots, window=window,
                             softcap=softcap, scale=scale,
-                            block_k=_cache_block(k.shape[1], block_k),
+                            block_k=_cache_block(k.shape[-2], block_k),
                             interpret=interpret)
 
 

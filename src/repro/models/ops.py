@@ -10,7 +10,7 @@ mode) and ``ctx`` carrying positions / lengths / mode.  FFNs return
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Optional
 
 import jax
@@ -33,6 +33,12 @@ class ApplyCtx:
     image_embeds: Optional[jax.Array] = None
     window: int = 0                # sliding window for local_attn layers
     remat: bool = False            # checkpoint each scanned block (train)
+    # Where the batch's state lives.  `layer` indexes a leaf stacked over
+    # the scanned blocks (None: the leaf is this layer's own); `slots` (B,)
+    # names the state row of each batch row (None: row b).  A slot past the
+    # last row is padding: the row writes no state.
+    layer: Optional[jax.Array] = None
+    slots: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -80,12 +86,35 @@ def _quant_kv(x):
     return q, scale
 
 
-def _update_cache(cache, new, idx):
-    """cache: (B, L, ...), new: (B, S, ...), idx: (B,) write offsets."""
-    def upd(c, u, i):
-        start = (i,) + (0,) * (c.ndim - 1)
-        return jax.lax.dynamic_update_slice(c, u.astype(c.dtype), start)
-    return jax.vmap(upd)(cache, new, idx)
+def read_state(leaf, ctx: ApplyCtx):
+    """The batch's rows of this layer's state leaf: (B, ...)."""
+    if ctx.layer is not None:
+        leaf = jax.lax.dynamic_index_in_dim(leaf, ctx.layer, keepdims=False)
+    if ctx.slots is not None:
+        leaf = jnp.take(leaf, ctx.slots, axis=0, mode="clip")
+    return leaf
+
+
+def write_state(leaf, new, ctx: ApplyCtx, start=None):
+    """`leaf` with the batch's new state written in place at this layer's
+    rows: whole rows `new` (B, ...), or with `start` (B,) the S positions
+    `new` (B, S, ...) of a sequence leaf from position start[b].  One
+    scatter of B windows; a padding slot's window is dropped."""
+    B = new.shape[0]
+    cols = [jnp.arange(B, dtype=jnp.int32) if ctx.slots is None
+            else ctx.slots.astype(jnp.int32)]
+    if ctx.layer is not None:
+        cols.insert(0, jnp.full((B,), ctx.layer, jnp.int32))
+    n_whole = len(cols)                 # layer and slot: one index each
+    if start is not None:
+        cols.append(start.astype(jnp.int32))
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(1, new.ndim)),
+        inserted_window_dims=tuple(range(n_whole)),
+        scatter_dims_to_operand_dims=tuple(range(len(cols))))
+    return jax.lax.scatter(
+        leaf, jnp.stack(cols, axis=1), new.astype(leaf.dtype), dnums,
+        unique_indices=True, mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
 
 def _sdpa(q, k, v, mask, scale, cap: float = 0.0, merged: bool = True):
@@ -163,29 +192,32 @@ def _heads_shardable(n_heads: int) -> bool:
 
 def _pallas_attn(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale,
                  interpret: bool):
-    """Attention through the Pallas kernels against the merged-head cache.
+    """Attention through the Pallas kernels against the merged-head cache,
+    which they read in place at the layer and slots of `ctx`.
 
     prefill -> chunked_prefill_attention (offset = chunk start);
     decode  -> flash-decode."""
     B = q.shape[0]
     if ctx.mode == "decode":
         return kops.decode_attention_op(
-            q[:, 0], kc, vc, ctx.positions[:, 0],
+            q[:, 0], kc, vc, ctx.positions[:, 0], ctx.layer, ctx.slots,
             window=ctx.window, softcap=float(cfg.attn_softcap),
             scale=scale, interpret=interpret)[:, None]
     offset = ctx.positions[:, 0]
     if ctx.lengths is not None:
         lengths = ctx.lengths
     else:
-        lengths = jnp.full((B,), kc.shape[1], jnp.int32)
+        lengths = jnp.full((B,), kc.shape[-2], jnp.int32)
     return kops.prefill_attention(
-        q, kc, vc, offset, lengths, window=ctx.window,
+        q, kc, vc, offset, lengths, ctx.layer, ctx.slots, window=ctx.window,
         softcap=float(cfg.attn_softcap), scale=scale, interpret=interpret)
 
 
 def _cache_sdpa(cfg: ModelConfig, q, kc, vc, ctx: ApplyCtx, scale):
     """The jnp path (and the kernels' reference): attention against the
-    merged-head cache, grouped so the cache layout stays undisturbed."""
+    batch's rows of the merged-head cache, grouped so the cache layout
+    stays undisturbed."""
+    kc, vc = read_state(kc, ctx), read_state(vc, ctx)
     B, L = kc.shape[:2]
     nkv, dh = cfg.num_kv_heads, cfg.head_dim_
     kc = shard(kc.reshape(B, L, nkv, dh), "batch", "ctx", "kv_heads", None)
@@ -240,37 +272,42 @@ def apply_attn(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
         # (0 for whole-prompt prefill, chunk start for chunked prefill,
         #  cur_len for decode).  The cache is merged-head (B, L, Hkv*D):
         # token-major with no padded minor dims, the kernels' layout.
-        write_idx = ctx.positions[:, 0]
+        start = ctx.positions[:, 0]
         merge = lambda a: a.reshape(*a.shape[:2], -1)  # noqa: E731
+        at = ctx                        # where the attention reads kc, vc
         if cfg.kv_cache_dtype == "int8":
             # quantized KV cache: per-(token, head) absmax scales
             kq, ks = _quant_kv(k)
             vq, vs = _quant_kv(v)
-            kcq = _update_cache(state["k"], merge(kq), write_idx)
-            vcq = _update_cache(state["v"], merge(vq), write_idx)
-            kss = _update_cache(state["k_scale"], ks, write_idx)
-            vss = _update_cache(state["v_scale"], vs, write_idx)
-            new_state = {**state, "k": kcq, "v": vcq,
-                         "k_scale": kss, "v_scale": vss}
-            dq = lambda c, sc: merge(  # noqa: E731
-                c.reshape(*c.shape[:2], nkv, dh).astype(x.dtype)
-                * sc[..., None].astype(x.dtype))
-            kc, vc = dq(kcq, kss), dq(vcq, vss)
+            new_state = {
+                **state,
+                "k": write_state(state["k"], merge(kq), ctx, start),
+                "v": write_state(state["v"], merge(vq), ctx, start),
+                "k_scale": write_state(state["k_scale"], ks, ctx, start),
+                "v_scale": write_state(state["v_scale"], vs, ctx, start)}
+
+            def dq(name):
+                c = read_state(new_state[name], ctx)
+                sc = read_state(new_state[f"{name}_scale"], ctx)
+                return merge(c.reshape(*c.shape[:2], nkv, dh).astype(x.dtype)
+                             * sc[..., None].astype(x.dtype))
+            kc, vc = dq("k"), dq("v")
+            at = replace(ctx, layer=None, slots=None)   # the batch's rows
         else:
             # reshard the new K/V to the CACHE layout before the in-place
             # update: without this GSPMD falls back to "involuntary full
             # rematerialization" (a whole-cache f32 all-gather per layer,
             # 722 GB/chip on gemma2 prefill)
-            kc = _update_cache(state["k"], shard(merge(k), "batch", "ctx",
-                                                 "kv_heads"), write_idx)
-            vc = _update_cache(state["v"], shard(merge(v), "batch", "ctx",
-                                                 "kv_heads"), write_idx)
+            kc = write_state(state["k"], shard(merge(k), "batch", "ctx",
+                                               "kv_heads"), ctx, start)
+            vc = write_state(state["v"], shard(merge(v), "batch", "ctx",
+                                               "kv_heads"), ctx, start)
             new_state = {**state, "k": kc, "v": vc}
         reference = lambda: _cache_sdpa(  # noqa: E731
-            cfg, q, kc, vc, ctx, scale)
+            cfg, q, kc, vc, at, scale)
         if _kernels_allowed(ctx):
             out = kops.by_platform(
-                lambda interpret: _pallas_attn(cfg, q, kc, vc, ctx, scale,
+                lambda interpret: _pallas_attn(cfg, q, kc, vc, at, scale,
                                                interpret), reference)
         else:
             out = reference()
@@ -305,10 +342,13 @@ def _apply_mla(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
         cc, kr = c_kv, k_rope
         k_pos = ctx.positions
     else:
-        write_idx = ctx.positions[:, 0]
-        cc = _update_cache(state["c_kv"], c_kv, write_idx)
-        kr = _update_cache(state["k_rope"], k_rope, write_idx)
-        new_state = {**state, "c_kv": cc, "k_rope": kr}
+        start = ctx.positions[:, 0]
+        new_state = {
+            **state,
+            "c_kv": write_state(state["c_kv"], c_kv, ctx, start),
+            "k_rope": write_state(state["k_rope"], k_rope, ctx, start)}
+        cc = read_state(new_state["c_kv"], ctx)
+        kr = read_state(new_state["k_rope"], ctx)
         L = cc.shape[1]
         k_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     mask = _causal_mask(ctx, ctx.positions, k_pos, ctx.lengths)[:, 0, 0]
@@ -355,7 +395,7 @@ def apply_cross_attn(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     q = rmsnorm(q, p["q_norm"])
     new_state = state
     if ctx.mode == "decode":
-        k, v = state["xk"], state["xv"]
+        k, v = read_state(state["xk"], ctx), read_state(state["xv"], ctx)
     else:
         assert ctx.image_embeds is not None, "vlm prefill needs image_embeds"
         ie = ctx.image_embeds.astype(x.dtype)
@@ -363,8 +403,8 @@ def apply_cross_attn(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
         v = (ie @ p["wv"]).reshape(B, -1, nkv, dh)
         k = rmsnorm(k, p["k_norm"])
         if state is not None:
-            new_state = {**state, "xk": k.astype(state["xk"].dtype),
-                         "xv": v.astype(state["xv"].dtype)}
+            new_state = {**state, "xk": write_state(state["xk"], k, ctx),
+                         "xv": write_state(state["xv"], v, ctx)}
     mask = jnp.ones((B, 1, 1, S, k.shape[1]), bool)
     out = _sdpa(q, k, v, mask, dh ** -0.5)
     out = out.reshape(B, S, nq * dh) @ p["wo"]
@@ -582,9 +622,10 @@ def apply_mamba(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     h = rmsnorm(x, p["ln1"], cfg.norm_plus_one)
     xi, z = _mamba_proj(cfg, p, h)
     xi = shard(xi, "batch", None, "ff")
-    conv0 = (state["conv"] if state is not None
+    conv0 = (read_state(state["conv"], ctx) if state is not None
              else jnp.zeros((B, mc.d_conv - 1, di), x.dtype))
-    ssm0 = (state["ssm"].astype(jnp.float32) if state is not None
+    ssm0 = (read_state(state["ssm"], ctx).astype(jnp.float32)
+            if state is not None
             else jnp.zeros((B, di, mc.d_state), jnp.float32))
     xc, conv1 = _mamba_conv_seq(p, xi, conv0)
     xc = _act(xc, "silu")
@@ -619,8 +660,8 @@ def apply_mamba(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     y = (y.astype(x.dtype) * _act(z, "silu")) @ p["out_proj"]
     new_state = state
     if state is not None:
-        new_state = {"ssm": hT.astype(state["ssm"].dtype),
-                     "conv": conv1.astype(state["conv"].dtype)}
+        new_state = {"ssm": write_state(state["ssm"], hT, ctx),
+                     "conv": write_state(state["conv"], conv1, ctx)}
     return y.astype(x.dtype), new_state
 
 
@@ -703,7 +744,7 @@ def apply_rwkv_tm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     K = cfg.rwkv_head_dim
     H = d // K
     h = rmsnorm(x, p["ln1"], cfg.norm_plus_one)
-    shift0 = (state["shift_t"] if state is not None
+    shift0 = (read_state(state["shift_t"], ctx) if state is not None
               else jnp.zeros((B, d), x.dtype))
     sx = _token_shift(h, shift0.astype(h.dtype))
     xxx = h + sx * p["mu_x"]
@@ -725,8 +766,8 @@ def apply_rwkv_tm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
         m = (ctx.positions < ctx.lengths[:, None])[:, :, None, None]
         w = jnp.where(m, w, 1.0)
         k = k * m
-    s0 = (state["wkv"].astype(jnp.float32) if state is not None
-          else jnp.zeros((B, H, K, K), jnp.float32))
+    s0 = (read_state(state["wkv"], ctx).astype(jnp.float32)
+          if state is not None else jnp.zeros((B, H, K, K), jnp.float32))
     if S == 1:
         y, sT = rwkv_wkv(r, k, v, w, u, s0)
     elif _kernels_allowed(ctx):
@@ -745,9 +786,9 @@ def apply_rwkv_tm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     out = (y.astype(x.dtype) * g) @ p["wo"]
     new_state = state
     if state is not None:
-        new_state = {**state, "wkv": sT.astype(state["wkv"].dtype),
-                     "shift_t": _last_valid(h, ctx).astype(
-                         state["shift_t"].dtype)}
+        new_state = {**state, "wkv": write_state(state["wkv"], sT, ctx),
+                     "shift_t": write_state(state["shift_t"],
+                                            _last_valid(h, ctx), ctx)}
     return out.astype(x.dtype), new_state
 
 
@@ -764,7 +805,7 @@ def _last_valid(h, ctx: ApplyCtx):
 def apply_rwkv_cm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     B, S, d = x.shape
     h = rmsnorm(x, p["ln2"], cfg.norm_plus_one)
-    shift0 = (state["shift_c"] if state is not None
+    shift0 = (read_state(state["shift_c"], ctx) if state is not None
               else jnp.zeros((B, d), x.dtype))
     sx = _token_shift(h, shift0.astype(h.dtype))
     xk = h + sx * p["mu_ck"]
@@ -774,6 +815,6 @@ def apply_rwkv_cm(cfg: ModelConfig, p, x, state, ctx: ApplyCtx):
     out = jax.nn.sigmoid(xr @ p["wr_cm"]) * kv
     new_state = state
     if state is not None:
-        new_state = {**state, "shift_c": _last_valid(h, ctx).astype(
-            state["shift_c"].dtype)}
+        new_state = {**state, "shift_c": write_state(
+            state["shift_c"], _last_valid(h, ctx), ctx)}
     return out.astype(x.dtype), new_state

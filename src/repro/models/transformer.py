@@ -14,6 +14,7 @@ Public API:
 from __future__ import annotations
 
 import math
+from dataclasses import replace
 from typing import Optional
 
 import jax
@@ -33,9 +34,7 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p, x, state,
                  ctx: ApplyCtx):
     """Residual layer = mixer + ffn. Returns (x, new_state, aux)."""
     window = cfg.sliding_window if spec.mixer == "local_attn" else 0
-    lctx = ApplyCtx(mode=ctx.mode, positions=ctx.positions,
-                    lengths=ctx.lengths, image_embeds=ctx.image_embeds,
-                    window=window)
+    lctx = replace(ctx, window=window)
     if spec.mixer in ("attn", "local_attn"):
         out, state = ops.apply_attn(cfg, p, x, state, lctx)
     elif spec.mixer == "cross_attn":
@@ -105,20 +104,24 @@ def _backbone(cfg: ModelConfig, params, x, state, ctx: ApplyCtx):
         (x, aux), _ = jax.lax.scan(body, (x, aux), params["blocks"])
         return x, None, aux
 
+    # The stacked state rides in the carry, so each layer updates its rows
+    # of it in place (`ops.write_state`) and the attention reads its layer
+    # where it lies: no layer's cache is sliced out and stacked anew.
     def body_s(carry, xs):
-        h, acc = carry
-        bp, bs = xs
-        new_bs = {}
+        h, acc, blocks = carry
+        bp, layer = xs
+        lctx = replace(ctx, layer=layer)
+        new_blocks = {}
         for i, spec in enumerate(pattern):
-            h, st, a = _apply_layer(cfg, spec, bp[f"p{i}"], h,
-                                    bs[f"p{i}"], ctx)
-            new_bs[f"p{i}"] = st
+            h, new_blocks[f"p{i}"], a = _apply_layer(
+                cfg, spec, bp[f"p{i}"], h, blocks[f"p{i}"], lctx)
             acc = acc + a
-        return (h, acc), new_bs
+        return (h, acc, new_blocks), None
 
-    (x, aux), new_blocks = jax.lax.scan(
-        body_s, (x, aux), (params["blocks"], state["blocks"]))
-    return x, {"prefix": new_prefix, "blocks": new_blocks}, aux
+    (x, aux, blocks), _ = jax.lax.scan(
+        body_s, (x, aux, state["blocks"]),
+        (params["blocks"], jnp.arange(cfg.num_blocks, dtype=jnp.int32)))
+    return x, {"prefix": new_prefix, "blocks": blocks}, aux
 
 
 # ---------------------------------------------------------------------------
@@ -141,18 +144,21 @@ def forward_train(cfg: ModelConfig, params, tokens,
 
 def prefill(cfg: ModelConfig, params, state, tokens, lengths,
             image_embeds: Optional[jax.Array] = None,
-            start: Optional[jax.Array] = None):
+            start: Optional[jax.Array] = None,
+            slots: Optional[jax.Array] = None):
     """Prompt processing; fills `state` at offset `start` (default 0).
 
     `lengths` is the ABSOLUTE valid length (start + valid tokens in this
     chunk) — chunked prefill passes consecutive windows with increasing
-    `start`.  Returns (last_token_logits (B,V), new_state)."""
+    `start`.  `slots` (B,) are the state rows the prompts fill (default
+    0..B-1), so a chunk can go straight into a pooled state.  Returns
+    (last_token_logits (B,V), new_state)."""
     B, S = tokens.shape
     if start is None:
         start = jnp.zeros((B,), jnp.int32)
     positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
     ctx = ApplyCtx(mode="prefill", positions=positions, lengths=lengths,
-                   image_embeds=image_embeds)
+                   image_embeds=image_embeds, slots=slots)
     x = _embed(cfg, params, tokens)
     x, new_state, _ = _backbone(cfg, params, x, state, ctx)
     # unembed ONLY the last valid position: the (B,S,V) logits tensor for a
@@ -163,14 +169,15 @@ def prefill(cfg: ModelConfig, params, state, tokens, lengths,
     return _unembed(cfg, params, x_last)[:, 0], new_state
 
 
-def decode_step(cfg: ModelConfig, params, state, last_tokens, cur_lens):
+def decode_step(cfg: ModelConfig, params, state, last_tokens, cur_lens,
+                slots: Optional[jax.Array] = None):
     """One autoregressive step against the cache.
 
-    last_tokens: (B,) int32; cur_lens: (B,) tokens already cached.
-    Returns (logits (B,V), new_state)."""
-    B = last_tokens.shape[0]
+    last_tokens: (B,) int32; cur_lens: (B,) tokens already cached; slots:
+    (B,) state rows (default 0..B-1), a row past the last being padding
+    that writes nothing.  Returns (logits (B,V), new_state)."""
     positions = cur_lens.astype(jnp.int32)[:, None]        # (B,1)
-    ctx = ApplyCtx(mode="decode", positions=positions)
+    ctx = ApplyCtx(mode="decode", positions=positions, slots=slots)
     x = _embed(cfg, params, last_tokens[:, None])
     x, new_state, _ = _backbone(cfg, params, x, state, ctx)
     return _unembed(cfg, params, x)[:, 0], new_state

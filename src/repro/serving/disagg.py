@@ -29,7 +29,7 @@ from repro.core.router import BurstDetector, Router, ttft_slo
 from repro.core.velocity import bucket_of
 from repro.models import init_state
 from repro.serving import kvtransfer
-from repro.serving.engine import Engine, Request, programs
+from repro.serving.engine import Engine, Request, jitted, programs
 from repro.serving.kvtransfer import TransferStats
 
 
@@ -44,7 +44,7 @@ class PrefillerInstance:
         self.queue: list[Request] = []
         self.tokens_done = 0
         self.wall_s = 1e-9
-        self._fn = programs(cfg)[0]
+        self._fn = jitted(programs(cfg)[0])
 
     # Alg. 1 interface -------------------------------------------------
     def inflight_tokens(self) -> float:

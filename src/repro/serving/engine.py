@@ -6,16 +6,19 @@ slots backed by a preallocated per-slot cache/state (the TPU analogue of
 vLLM's paged KV pool — slot-contiguous rather than paged, page granularity
 traded for XLA-static shapes; see DESIGN.md).
 
-Three jitted programs, built once per config by ``programs`` and shared by
-every instance of it:
+Three programs, built once per config by ``programs``, jitted with their
+state donated by ``jitted`` and shared by every instance of it:
 
   * ``_prefill``      whole-prompt prefill of one request (batch-1 state)
   * ``_decode``       one token for every active slot
   * ``_mixed``        the Convertible-Decoder step: decode for active slots
-                      FUSED with one restricted prefill chunk (XLA compiles
-                      a single program — decode's idle MXU cycles absorb the
-                      chunk, the TPU analogue of the paper's chunked-prefill
-                      co-location)
+                      FUSED with one restricted prefill chunk written
+                      straight into its slot (XLA compiles a single program
+                      — decode's idle MXU cycles absorb the chunk, the TPU
+                      analogue of the paper's chunked-prefill co-location)
+
+Donation lets each program update the pooled cache in place: after a call
+the engine holds only the state the program returned.
 
 The SLO-aware chunk size / memory reservation policy that *drives* ``_mixed``
 lives in ``repro.core.convertible``.
@@ -115,7 +118,8 @@ def _read_slot(pool, slot):
 
 @functools.lru_cache(maxsize=None)
 def programs(cfg: ModelConfig):
-    """The three jitted programs of every instance serving `cfg`.
+    """The three programs of every instance serving `cfg`, as functions of
+    (params, state, ...) returning the new state after the logits.
 
     Built once per config and shared, so an instance the autoscaler boots
     reuses what earlier instances traced and compiled for the same shapes."""
@@ -128,15 +132,27 @@ def programs(cfg: ModelConfig):
         return decode_step(cfg, params, state, last_tokens, cur_lens)
 
     def _mixed(params, state, last_tokens, cur_lens,
-               chunk_state, chunk_tokens, chunk_len, chunk_start):
-        """Fused decode + restricted prefill chunk (single XLA program)."""
-        logits, new_state = decode_step(cfg, params, state,
-                                        last_tokens, cur_lens)
-        clog, new_cstate = prefill(cfg, params, chunk_state, chunk_tokens,
-                                   chunk_len, start=chunk_start)
-        return logits, new_state, clog, new_cstate
+               slot, chunk_tokens, chunk_len, chunk_start):
+        """Fused decode + restricted prefill chunk (single XLA program).
 
-    return jax.jit(_prefill_one), jax.jit(_decode_all), jax.jit(_mixed)
+        `slot` (1,) is the chunk's row of the pool: its decode row is
+        padding, which writes nothing, and the chunk fills it in place."""
+        rows = jnp.arange(last_tokens.shape[0], dtype=jnp.int32)
+        logits, state = decode_step(
+            cfg, params, state, last_tokens, cur_lens,
+            slots=jnp.where(rows == slot, rows.shape[0], rows))
+        clog, state = prefill(cfg, params, state, chunk_tokens, chunk_len,
+                              start=chunk_start, slots=slot)
+        return logits, state, clog
+
+    return _prefill_one, _decode_all, _mixed
+
+
+@functools.lru_cache(maxsize=None)
+def jitted(program):
+    """`program` jitted with its state (argument 1) donated, so it updates
+    the cache in place.  One jitted function per program, shared."""
+    return jax.jit(program, donate_argnums=1)
 
 
 class Engine:
@@ -158,7 +174,8 @@ class Engine:
         self.pending_chunked: Optional[Request] = None
         self.now = 0.0                        # virtual clock (tests/sim)
         self.steps = {"decode": 0, "mixed": 0}
-        self._prefill, self._decode, self._mixed = programs(cfg)
+        self._prefill, self._decode, self._mixed = map(jitted,
+                                                       programs(cfg))
 
     # ------------------------------------------------------------------
     def free_slots(self) -> int:
@@ -268,14 +285,12 @@ class Engine:
         n = min(C, L - start)
         chunk[0, :n] = req.prompt[start:start + n]
         slot = req.slot
-        st1 = _read_slot(self.state, slot)
-        logits, self.state, clog, st1 = self._mixed(
+        logits, self.state, clog = self._mixed(
             self.params, self.state,
             jnp.asarray(self.last_tokens), jnp.asarray(self.cur_lens),
-            st1, jnp.asarray(chunk),
+            jnp.array([slot], jnp.int32), jnp.asarray(chunk),
             jnp.array([min(L, start + n)], jnp.int32),
             jnp.array([start], jnp.int32))
-        self.state = _write_slot(self.state, st1, slot)
         req.prefill_done += n
         out = self._commit_decode(logits, skip_slot=slot)
         if req.prefill_done >= L:

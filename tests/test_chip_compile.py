@@ -98,20 +98,28 @@ def test_wkv6_compiles(one_chip, arch):
 
 
 def test_qwen2_decode_step_compiles_on_kernels(one_chip):
-    """A whole Qwen2-0.5B decode step (24 layers, 32 slots x 4096
-    positions), traced on this host and lowered for the described chip,
-    takes the kernel path by itself and fits one v5e's 16 GB."""
-    from repro.models import abstract_params, abstract_state, decode_step
+    """The engine's decode program for Qwen2-0.5B (24 layers, 32 slots x
+    4096 positions), traced on this host and lowered for the described
+    chip, takes the kernel path by itself, fits one v5e's 16 GB, and updates
+    the donated cache in place: the state is aliased to the output and no
+    temporary holds as much as one layer of it."""
+    from repro.models import abstract_params, abstract_state
+    from repro.serving.engine import jitted, programs
     cfg = get_config("qwen2-0.5b")
     B, L = 32, 4096
     place = lambda t: jax.tree.map(  # noqa: E731
         lambda a: _spec(one_chip, a.shape, a.dtype), t)
-    compiled = _compile(
-        lambda p, s, t, c: decode_step(cfg, p, s, t, c),
-        place(abstract_params(cfg)), place(abstract_state(cfg, B, L)),
-        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B,), jnp.int32))
+    state = place(abstract_state(cfg, B, L))
+    compiled = jitted(programs(cfg)[1]).lower(
+        place(abstract_params(cfg)), state,
+        _spec(one_chip, (B,), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    cache = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= cache
+    assert mem.temp_size_in_bytes < cache / cfg.num_layers
 
 
 def test_decode_step_under_a_mesh_keeps_jnp_path(topo, one_chip):

@@ -117,6 +117,50 @@ def test_decode_attention(B, L, Hq, Hkv, D, window):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 3])
+def test_decode_attention_reads_a_stacked_cache_in_place(layer):
+    """The model's stacked (NB, P, L, Hkv*D) cache read at `layer`, with
+    batch row b at pool row slots[b], equals the reference on that layer's
+    rows; a padding slot past the pool leaves the other rows alone."""
+    NB, P, B, L, Hq, Hkv, D = 4, 5, 3, 64, 8, 2, 64
+    q = _rnd(B, Hq, D)
+    k = _rnd(NB, P, L, Hkv, D)
+    v = _rnd(NB, P, L, Hkv, D)
+    cur = jnp.asarray(RNG.randint(0, L, size=(B,)), jnp.int32)
+    slots = jnp.array([4, 0, 2], jnp.int32)
+    stacked = lambda x: x.reshape(NB, P, L, Hkv * D)  # noqa: E731
+    out = decode_attention_op(q, stacked(k), stacked(v), cur,
+                              jnp.int32(layer), slots, block_k=16,
+                              interpret=True)
+    want = ref.decode_attention_ref(q, k[layer, slots], v[layer, slots], cur)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    padded = decode_attention_op(q, stacked(k), stacked(v), cur,
+                                 jnp.int32(layer), slots.at[0].set(P),
+                                 block_k=16, interpret=True)
+    np.testing.assert_allclose(np.asarray(padded[1:]), np.asarray(want[1:]),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_chunked_prefill_attention_reads_a_stacked_cache_in_place(layer):
+    """A chunk against `layer` of the stacked cache at pool row `slot`
+    equals the reference on that layer's row."""
+    NB, P, Sq, Skv, Hq, Hkv, D = 3, 4, 16, 48, 4, 2, 64
+    q = _rnd(1, Sq, Hq, D)
+    k = _rnd(NB, P, Skv, Hkv, D)
+    v = _rnd(NB, P, Skv, Hkv, D)
+    off, lens = jnp.array([16], jnp.int32), jnp.array([30], jnp.int32)
+    slot = jnp.array([3], jnp.int32)
+    stacked = lambda x: x.reshape(NB, P, Skv, Hkv * D)  # noqa: E731
+    out = prefill_attention(q, stacked(k), stacked(v), off, lens,
+                            jnp.int32(layer), slot, interpret=True)
+    want = ref.chunked_prefill_attention_ref(q, k[layer, slot],
+                                             v[layer, slot], off, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_decode_attention_block_skipping():
     """Blocks beyond cur_len are skipped: result must not depend on garbage
     in the dead region."""
